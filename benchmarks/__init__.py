@@ -4,7 +4,7 @@ Must run before anything imports jax (``python -m benchmarks.run``
 imports this first), so the engine-scaling sweep can exercise the
 mesh-sharded engine's device-count axis on CPU. No-op when the flag is
 already set or when jax was imported earlier in the process — the
-sharded engine then clamps to however many devices exist.
+engine-scaling sweep then skips device counts that do not exist.
 """
 import os
 

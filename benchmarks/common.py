@@ -7,15 +7,15 @@ import time
 
 import numpy as np
 
-from repro.kernels._compat import enable_compile_cache
+from repro.kernels._compat import configure_compile_cache
 
 QUICK = os.environ.get("BENCH_FULL", "0") != "1"
 
-# Opt into JAX's persistent compilation cache (REPRO_COMPILE_CACHE=dir)
-# before any benchmark traces a program: repeat runs then skip the XLA
-# compile entirely, which keeps quick-mode timings about the engines
-# rather than about tracing. No-op when the knob is unset.
-COMPILE_CACHE_DIR = enable_compile_cache()
+# Place JAX's persistent compilation cache (JAX_COMPILATION_CACHE_DIR,
+# else <checkout>/.jax_cache) before any benchmark traces a program:
+# repeat runs then skip the XLA compile entirely, which keeps
+# quick-mode timings about the engines rather than about tracing.
+configure_compile_cache()
 
 # dataset scales: quick mode keeps the full suite ~ minutes on CPU;
 # BENCH_FULL=1 runs the paper-scale graphs (github full scale).

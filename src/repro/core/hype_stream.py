@@ -423,10 +423,13 @@ def hype_stream_partition(hg: Hypergraph, k: int,
     and feeds ``apply_updates``). Balance: ``max - min <= k`` via the
     hard ``ceil(n/k)`` capacity cap.
     """
+    from repro.kernels._compat import configure_compile_cache
+
     p = params or StreamParams()
     _validate_params(p)
     if k < 1:
         raise ValueError(f"k must be >= 1, got {k}")
+    configure_compile_cache()
     state = _fresh_state(hg, k, p)
     if k == 1 or hg.n == 0:
         state.assignment[:] = 0 if k >= 1 else -1

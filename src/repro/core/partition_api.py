@@ -503,8 +503,8 @@ def partition_resilient(hg: Hypergraph, k: int,
             # published; with no snapshot_dir they cold-start instead.
             resume = snapshot_dir
     dt = time.perf_counter() - t0
-    if hasattr(stats, "fallbacks"):
-        stats.fallbacks = len(attempted)
+    if hasattr(stats, "fallbacks"):     # plus the engine's own hand-offs
+        stats.fallbacks += len(attempted)
     rep = metrics.all_metrics(hg, assignment, k)
     rep.update(method=cur, requested_method=method, k=k, runtime_s=dt,
                degraded_from=attempted, fallbacks=len(attempted),

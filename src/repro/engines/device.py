@@ -434,7 +434,8 @@ def hype_device_partition(hg: Hypergraph, k: int,
     ``pipeline_depth=1`` with matching knobs. Falls back to
     ``hype_superstep_partition`` when the int32 encoding gates or the
     memory plan reject the graph, and down the §4g rung ladder (via the
-    host pipeline) on device OOM.
+    host pipeline) on device OOM; each hand-off counts in
+    ``stats.fallbacks``.
     """
     if params is None:
         params = DeviceParams()
@@ -490,14 +491,20 @@ def hype_device_partition(hg: Hypergraph, k: int,
                     f"device memory rungs exhausted: {exc2}") from exc2
         if assignment is None:
             from .batched import hype_batched_partition
-            return hype_batched_partition(hg, k, params, return_stats)
+            assignment, stats = hype_batched_partition(
+                hg, k, params, return_stats=True)
+            stats.fallbacks += 1
+            return (assignment, stats) if return_stats else assignment
         pst.stats.fallbacks += 1
         assert (assignment >= 0).all()
         assignment = maybe_refine(hg, k, params, assignment, pst.stats)
         return (assignment, pst.stats) if return_stats else assignment
     if assignment is None:
         from .superstep import hype_superstep_partition
-        return hype_superstep_partition(hg, k, params, return_stats)
+        assignment, stats = hype_superstep_partition(hg, k, params,
+                                                     return_stats=True)
+        stats.fallbacks += 1
+        return (assignment, stats) if return_stats else assignment
     assert (assignment >= 0).all()
     assignment = maybe_refine(hg, k, params, assignment, st.stats)
     if return_stats:

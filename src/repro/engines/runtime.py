@@ -7,7 +7,7 @@ once:
   * ``EngineRuntime`` — the host-side state core every engine extends:
     assignment/pool bookkeeping, the deterministic random stream,
     fault-injection + bounded-retry device calls (``_guarded_kernel``,
-    core/resilience.py §4f), and the compile-cache opt-in.
+    core/resilience.py §4f), and the compile-cache placement.
   * ``BatchedStats`` — the family-wide counter dataclass, plus ``merge``
     for combining the stats of split or restarted runs.
   * ``SnapshotMixin`` — snapshot capture / exact restore / cross-engine
@@ -89,8 +89,10 @@ class BatchedStats:
     retries: int = 0                # transient-fault retries + poisoned-
     #                                 superstep replays (never counted as
     #                                 extra kernel_calls / supersteps)
-    fallbacks: int = 0              # ladder rungs exhausted before this
-    #                                 engine ran (partition_resilient)
+    fallbacks: int = 0              # hand-offs to another engine: ladder
+    #                                 rungs exhausted before this engine
+    #                                 ran (partition_resilient) plus
+    #                                 in-engine fallbacks to a lower one
     snapshots: int = 0              # checkpoints published
     snapshot_s: float = 0.0         # wall-clock publishing checkpoints
     restore_s: float = 0.0          # wall-clock restoring the resume ckpt
@@ -151,10 +153,9 @@ class EngineRuntime:
     """
 
     def __init__(self, hg: Hypergraph, k: int, p):
-        # opt into the persistent XLA compile cache (REPRO_COMPILE_CACHE)
-        # before any engine traces a kernel; idempotent no-op when unset
-        from repro.kernels._compat import enable_compile_cache
-        enable_compile_cache()
+        # place the persistent compile cache before any engine traces
+        from repro.kernels._compat import configure_compile_cache
+        configure_compile_cache()
         self.hg = hg
         self.k = k
         self.p = p

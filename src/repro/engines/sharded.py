@@ -39,7 +39,8 @@ class ShardedParams(SuperstepParams):
 
     Inherits every superstep knob. ``devices`` sets the 1-D mesh size the
     k phase groups are sharded over; ``None`` uses every local JAX device
-    (capped at ``k``). On CPU, simulate a mesh with
+    (capped at ``k``), and more devices than exist is an error. On CPU,
+    simulate a mesh with
     ``XLA_FLAGS=--xla_force_host_platform_device_count=4``.
     """
     devices: Optional[int] = None
@@ -73,7 +74,6 @@ def _sharded_program(num_devices: int, group_l: int, tile_l: int,
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     from repro.kernels.hype_score.kernel import SELECT_PAD
     from repro.kernels.hype_score.ops import hype_score_select_shard
 
@@ -190,10 +190,10 @@ def _sharded_program(num_devices: int, group_l: int, tile_l: int,
     rep = P()     # every array is replicated; devices differ via axis_index
     # poison undonated for the same reason as _pipeline_program: older
     # in-flight handles must still be able to read their poison output.
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         step, mesh=mesh,
         in_specs=(rep,) * 16, out_specs=(rep,) * 7,
-        check_rep=False), donate_argnums=(2, 3, 4))
+        check_vma=False), donate_argnums=(2, 3, 4))
 
 
 def sharded_superstep_device(indptr, indices, assign, cache, acc,
@@ -369,13 +369,14 @@ def hype_sharded_partition(hg: Hypergraph, k: int,
     and losers redraw from their pools next superstep.
 
     ``params.devices`` picks the mesh size (default: all local devices,
-    capped at ``k``); on CPU simulate devices with
+    capped at ``k``; asking for more devices than exist raises
+    ``ValueError``); on CPU simulate devices with
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N``. With one
     device the engine degenerates to (slightly reordered) single-device
     superstep growth. Supersteps run on the shared double-buffered
     pipeline (``params.pipeline_depth``, DESIGN.md §4d). Falls back to
     ``hype_superstep_partition``'s own fallback chain when the
-    adjacency guard trips.
+    adjacency guard trips; each hand-off counts in ``stats.fallbacks``.
     """
     if params is None:
         params = ShardedParams()
@@ -396,15 +397,21 @@ def hype_sharded_partition(hg: Hypergraph, k: int,
         return (out, BatchedStats()) if return_stats else out
     import jax
     avail = len(jax.devices())
-    num = params.devices if params.devices is not None else avail
-    num = max(1, min(num, avail, k))
+    if params.devices is not None and params.devices > avail:
+        raise ValueError(
+            f"devices={params.devices} but only {avail} JAX devices "
+            f"exist")
+    num = min(params.devices or avail, k)
     kG = (-(-k // num)) * num       # phase groups padded to the mesh
     assignment, st = _run_pipeline_budgeted(
         hg, k, params,
         lambda p2, rung: ShardedState(hg, kG, p2, num, mem_rung=rung),
         "hype_sharded", devices=num)
     if assignment is None:
-        return hype_superstep_partition(hg, k, params, return_stats)
+        assignment, stats = hype_superstep_partition(hg, k, params,
+                                                     return_stats=True)
+        stats.fallbacks += 1
+        return (assignment, stats) if return_stats else assignment
     assert (assignment >= 0).all()
     assignment = maybe_refine(hg, k, params, assignment, st.stats)
     if return_stats:

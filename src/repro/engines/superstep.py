@@ -569,7 +569,7 @@ def hype_superstep_partition(hg: Hypergraph, k: int,
     mirrors N-1's admissions and packs N+1; ``pipeline_depth=1`` is the
     lock-step schedule, bit for bit. Falls back to
     ``hype_batched_partition`` when the adjacency guard trips
-    (pathological hub expansion).
+    (pathological hub expansion), counted in ``stats.fallbacks``.
     """
     if params is None:
         params = SuperstepParams()
@@ -589,7 +589,10 @@ def hype_superstep_partition(hg: Hypergraph, k: int,
     assignment, st = run_pipeline_budgeted(hg, k, params)
     if assignment is None:
         from .batched import hype_batched_partition
-        return hype_batched_partition(hg, k, params, return_stats)
+        assignment, stats = hype_batched_partition(hg, k, params,
+                                                   return_stats=True)
+        stats.fallbacks += 1
+        return (assignment, stats) if return_stats else assignment
     assert (assignment >= 0).all()
     assignment = maybe_refine(hg, k, params, assignment, st.stats)
     if return_stats:

@@ -1,15 +1,14 @@
-"""Pallas API compatibility shims shared by all kernels."""
-import functools
+"""Runtime switches shared by all kernels and engines."""
 import os
-
-from jax.experimental.pallas import tpu as pltpu
-
-# jax >= 0.5 renamed TPUCompilerParams -> CompilerParams
-CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
+import pathlib
 
 _TRUTHY = ("1", "true", "yes", "on")
 _FALSY = ("0", "false", "no", "off")
+
+# <checkout>/.jax_cache: fixed, because the directory is part of the
+# cache key — a path that moved between runs would never hit
+DEFAULT_CACHE_DIR = str(
+    pathlib.Path(__file__).resolve().parents[3] / ".jax_cache")
 
 
 def pallas_interpret() -> bool:
@@ -36,29 +35,22 @@ def pallas_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-@functools.lru_cache(maxsize=1)
-def enable_compile_cache() -> str | None:
-    """Opt into JAX's persistent compilation cache via env knob.
+def configure_compile_cache() -> str:
+    """Place JAX's persistent compilation cache; returns the directory.
 
-    ``REPRO_COMPILE_CACHE`` names a directory to store compiled
-    executables across processes; unset or falsy leaves caching off.
-    The repro engines retrace identical while_loop/kernel programs on
-    every cold start — for the device-resident loop that single XLA
-    compile dominates small-graph wall time — so benchmarks and CI set
-    this to amortise it. Min compile-time / entry-size thresholds are
-    zeroed so the many small Pallas kernels qualify, not just the
-    megakernel. Idempotent (cached); returns the directory in use, or
-    ``None`` when disabled. Safe on jax builds without the persistent
-    cache: config failures disable silently rather than break the run.
+    ``JAX_COMPILATION_CACHE_DIR`` wins when set; otherwise the cache
+    lives at the fixed ``DEFAULT_CACHE_DIR`` inside the checkout. The
+    engines retrace identical while_loop/kernel programs on every cold
+    start, so the min compile-time / entry-size thresholds are zeroed
+    to let the many small Pallas kernels qualify, not just the
+    megakernel. ``JAX_ENABLE_COMPILATION_CACHE=false`` still turns the
+    cache off (the test suite does). Called at every engine entry;
+    repeated calls set the same values.
     """
-    path = os.environ.get("REPRO_COMPILE_CACHE", "").strip()
-    if not path or path.lower() in _FALSY:
-        return None
     import jax
-    try:
-        jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception:
-        return None
+
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or DEFAULT_CACHE_DIR
+    jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     return path

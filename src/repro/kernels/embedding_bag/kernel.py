@@ -18,9 +18,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-from repro.kernels._compat import CompilerParams as _CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _bag_kernel(ids_ref, row_ref, out_ref, acc_ref, cnt_ref, *,
@@ -74,7 +72,7 @@ def embedding_bag_kernel(table, ids, *, combine: str = "mean",
         functools.partial(_bag_kernel, combine=combine),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, D), table.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(ids, table)

@@ -19,9 +19,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
 
 NEG_INF = -1e30
 
@@ -127,7 +126,7 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True,
             pltpu.VMEM((block_q, 1), jnp.float32),   # running denom
             pltpu.VMEM((block_q, D), jnp.float32),   # output accumulator
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -22,9 +22,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-from repro.kernels._compat import CompilerParams as _CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _score_kernel(fringe_ref, nbrs_ref, out_ref):
@@ -56,7 +54,7 @@ def hype_scores_kernel(nbrs, fringe, *, tile_b: int = 256,
         ],
         out_specs=pl.BlockSpec((tile_b, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, 1), jnp.int32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(fringe2d, nbrs)
@@ -88,43 +86,66 @@ def _score_select_kernel(fringe_ref, prev_ref, bias_ref, nbrs_ref,
     so one kernel call performs refill-scoring plus the multi-admission
     selection the host used to argsort for. Selected indices < rows refer
     to fresh tile rows, >= rows to pool slots.
-    """
-    nbrs = nbrs_ref[...]                      # (TG * rows, L)
-    fringe = fringe_ref[...]                  # (TG, s)
-    prev = prev_ref[...]                      # (TG, P)
-    tg = fringe.shape[0]
-    valid = nbrs >= 0
-    member = jnp.zeros_like(valid)
-    for j in range(fringe.shape[-1]):         # s is a small static constant
-        fj = jnp.repeat(fringe[:, j], rows)[:, None]   # phase -> its rows
-        member = jnp.logical_or(member, nbrs == fj)
-    member = jnp.logical_and(member, valid)
-    score = (valid.sum(axis=1) - member.sum(axis=1)).astype(jnp.float32)
-    score = score + bias_ref[...][:, 0]
-    score_ref[...] = score[:, None]
 
-    # merge fresh scores with the held pool scores; clamp +inf pads to the
+    Every value stays 2-D with phases on sublanes — Mosaic rejects the
+    1-D <-> 2-D shape casts a flat row layout needs. Row ``r`` of all TG
+    phases is one (TG, L) load, so the phase's fringe is a (TG, 1)
+    column broadcast along lanes and the row's score lands in lane ``r``
+    of a (TG, rows) tile; selection keeps the fresh (TG, rows) and pool
+    (TG, P) tiles apart and writes each pick into its output lane.
+    """
+    fringe = fringe_ref[...]                  # (TG, s)
+    tg = fringe.shape[0]
+    fcols = [fringe[:, j:j + 1] for j in range(fringe.shape[1])]
+    pos_f = jax.lax.broadcasted_iota(jnp.int32, (tg, rows), 1)
+
+    def score_row(r, fresh):
+        nbrs = nbrs_ref[:, r, :]              # (TG, L): row r per phase
+        valid = nbrs >= 0
+        member = jnp.zeros_like(valid)
+        for fj in fcols:                      # s is a small static constant
+            member = jnp.logical_or(member, nbrs == fj)
+        member = jnp.logical_and(member, valid)
+        score = (valid.sum(axis=1, keepdims=True)
+                 - member.sum(axis=1, keepdims=True))
+        return jnp.where(pos_f == r, score.astype(jnp.float32), fresh)
+
+    fresh = jax.lax.fori_loop(0, rows, score_row,
+                              jnp.zeros((tg, rows), jnp.float32))
+    fresh = fresh + bias_ref[...]
+    score_ref[...] = fresh
+
+    # select over [fresh rows | pool slots]; clamp +inf pads to the
     # finite SELECT_PAD so the exclusion sentinel (+inf) stays unique.
-    merged = jnp.concatenate([score.reshape(tg, rows), prev], axis=1)
-    merged = jnp.minimum(merged, jnp.float32(SELECT_PAD))
-    n_slots = merged.shape[1]
-    pos = jax.lax.broadcasted_iota(jnp.int32, merged.shape, 1)
-    sel_i, sel_v = [], []
-    for _ in range(select_k):                 # select_k is small and static
-        mv = jnp.min(merged, axis=1, keepdims=True)          # (TG, 1)
-        am = jnp.min(jnp.where(merged == mv, pos, n_slots), axis=1)
-        sel_i.append(am)
-        sel_v.append(mv[:, 0])
-        merged = jnp.where(pos == am[:, None], jnp.float32(jnp.inf),
-                           merged)
-    idx_ref[...] = jnp.stack(sel_i, axis=1).astype(jnp.int32)
-    val_ref[...] = jnp.stack(sel_v, axis=1).astype(jnp.float32)
+    pad = jnp.float32(SELECT_PAD)
+    fresh = jnp.minimum(fresh, pad)
+    prev = jnp.minimum(prev_ref[...], pad)    # (TG, P)
+    n_slots = rows + prev.shape[1]
+    pos_p = jax.lax.broadcasted_iota(jnp.int32, prev.shape, 1) + rows
+    out_col = jax.lax.broadcasted_iota(jnp.int32, (tg, select_k), 1)
+    sel_i = jnp.zeros((tg, select_k), jnp.int32)
+    sel_v = jnp.zeros((tg, select_k), jnp.float32)
+    for i in range(select_k):                 # select_k is small and static
+        mv = jnp.minimum(jnp.min(fresh, axis=1, keepdims=True),
+                         jnp.min(prev, axis=1, keepdims=True))   # (TG, 1)
+        am = jnp.minimum(
+            jnp.min(jnp.where(fresh == mv, pos_f, n_slots), axis=1,
+                    keepdims=True),
+            jnp.min(jnp.where(prev == mv, pos_p, n_slots), axis=1,
+                    keepdims=True))
+        sel_i = jnp.where(out_col == i, am, sel_i)
+        sel_v = jnp.where(out_col == i, mv, sel_v)
+        fresh = jnp.where(pos_f == am, jnp.float32(jnp.inf), fresh)
+        prev = jnp.where(pos_p == am, jnp.float32(jnp.inf), prev)
+    idx_ref[...] = sel_i
+    val_ref[...] = sel_v
     # refill trigger: real candidates left per phase AFTER selection
     # (selected slots are +inf, pads/empties sit at SELECT_PAD). The
     # device-resident loop reads this to decide which phases need a
     # pool refill next superstep without a host round-trip.
-    rem_ref[...] = (merged < jnp.float32(SELECT_PAD)).sum(
-        axis=1).astype(jnp.int32)[:, None]
+    rem_ref[...] = ((fresh < pad).sum(axis=1, keepdims=True)
+                    + (prev < pad).sum(axis=1, keepdims=True)
+                    ).astype(jnp.int32)
 
 
 def hype_score_select_kernel(nbrs, fringe, bias, prev, *, select_k: int,
@@ -164,25 +185,25 @@ def hype_score_select_kernel(nbrs, fringe, bias, prev, *, select_k: int,
         in_specs=[
             pl.BlockSpec((tile_g, s), lambda g: (g, 0)),
             pl.BlockSpec((tile_g, P), lambda g: (g, 0)),
-            pl.BlockSpec((tile_g * R, 1), lambda g: (g, 0)),
-            pl.BlockSpec((tile_g * R, L), lambda g: (g, 0)),
+            pl.BlockSpec((tile_g, R), lambda g: (g, 0)),
+            pl.BlockSpec((tile_g, R, L), lambda g: (g, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((tile_g * R, 1), lambda g: (g, 0)),
+            pl.BlockSpec((tile_g, R), lambda g: (g, 0)),
             pl.BlockSpec((tile_g, select_k), lambda g: (g, 0)),
             pl.BlockSpec((tile_g, select_k), lambda g: (g, 0)),
             pl.BlockSpec((tile_g, 1), lambda g: (g, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, 1), jnp.float32),
+            jax.ShapeDtypeStruct((G, R), jnp.float32),
             jax.ShapeDtypeStruct((G, select_k), jnp.int32),
             jax.ShapeDtypeStruct((G, select_k), jnp.float32),
             jax.ShapeDtypeStruct((G, 1), jnp.int32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(fringe, prev, bias[:, None], nbrs)
+    )(fringe, prev, bias.reshape(G, R), nbrs.reshape(G, R, L))
     if with_remaining:
-        return scores[:, 0], idx, val, rem[:, 0]
-    return scores[:, 0], idx, val
+        return scores.reshape(B), idx, val, rem[:, 0]
+    return scores.reshape(B), idx, val

@@ -34,9 +34,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-from repro.kernels._compat import CompilerParams as _CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _gain_kernel(own_ref, parts_ref, out_ref, *, k: int):
@@ -71,7 +69,7 @@ def kway_gains_kernel(parts, own, *, k: int, tile_b: int = 256,
         ],
         out_specs=pl.BlockSpec((tile_b, k), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, k), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(own[:, None], parts)

@@ -18,9 +18,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-from repro.kernels._compat import CompilerParams as _CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _agg_kernel(nbrs_ref, row_ref, w_ref, out_ref, acc_ref, cnt_ref):
@@ -73,7 +71,7 @@ def neighbor_agg_kernel(x, nbrs, w, *, interpret: bool = False):
         _agg_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, F), x.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(nbrs, x, w)

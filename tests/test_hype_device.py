@@ -204,33 +204,30 @@ def test_device_interpret_mode(monkeypatch, hg, dev_16_8):
     np.testing.assert_array_equal(a, dev_16_8[0])
 
 
-# ------------------------------------------- compile-cache env knob
+# ------------------------------------------- compile-cache placement
 
 def test_compile_cache_env_knob(monkeypatch, tmp_path):
-    """REPRO_COMPILE_CACHE wires the persistent XLA compile cache:
-    unset/falsy leaves it off, a path turns it on (idempotently)."""
+    """JAX_COMPILATION_CACHE_DIR places the persistent compile cache
+    when set; otherwise it sits at the fixed <checkout>/.jax_cache, and
+    no other directory is ever configured."""
+    import pathlib
+
+    import jax
     from repro.kernels import _compat
+    before = jax.config.jax_compilation_cache_dir
     cc = str(tmp_path / "cc")
+    default = str(pathlib.Path(__file__).resolve().parents[1]
+                  / ".jax_cache")
     try:
-        _compat.enable_compile_cache.cache_clear()
-        monkeypatch.delenv("REPRO_COMPILE_CACHE", raising=False)
-        assert _compat.enable_compile_cache() is None
-        _compat.enable_compile_cache.cache_clear()
-        monkeypatch.setenv("REPRO_COMPILE_CACHE", "off")
-        assert _compat.enable_compile_cache() is None
-        _compat.enable_compile_cache.cache_clear()
-        monkeypatch.setenv("REPRO_COMPILE_CACHE", cc)
-        assert _compat.enable_compile_cache() == cc
-        # cached: a second call must not re-read the (changed) env
-        monkeypatch.setenv("REPRO_COMPILE_CACHE", "0")
-        assert _compat.enable_compile_cache() == cc
-    finally:
-        _compat.enable_compile_cache.cache_clear()
-        import jax
-        try:     # leave the process-global config as we found it
-            jax.config.update("jax_compilation_cache_dir", None)
-        except Exception:
-            pass
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", cc)
+        assert _compat.configure_compile_cache() == cc
+        assert jax.config.jax_compilation_cache_dir == cc
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        for _ in range(2):      # the same path on every call
+            assert _compat.configure_compile_cache() == default
+            assert jax.config.jax_compilation_cache_dir == default
+    finally:     # leave the process-global config as we found it
+        jax.config.update("jax_compilation_cache_dir", before)
 
 
 # -------------------------------------------------- parameter contract
@@ -256,6 +253,8 @@ def test_device_unsupported_falls_back(hg):
     # bud * 2^CLS_CLAMP reaches 2^31: the stage-A cumsum could overflow
     assert not device_loop.supported(n=10, m=100, kG=4, bud=1 << 13)
     # rows=2048 -> bud=8192 trips the same gate through the public API
-    a = hype_device_partition(hg, 3, DeviceParams(seed=0, t=8,
-                                                  rows=2048))
+    a, st = hype_device_partition(hg, 3, DeviceParams(seed=0, t=8,
+                                                      rows=2048),
+                                  return_stats=True)
     assert (a >= 0).all() and (a < 3).all()
+    assert st.fallbacks == 1    # the hand-off to hype_superstep shows

@@ -66,6 +66,14 @@ def test_sharded_single_device_degenerates(hg):
     assert sizes.max() - sizes.min() <= 1
 
 
+def test_sharded_rejects_more_devices_than_exist(hg):
+    """Asking for a mesh wider than the machine raises instead of
+    silently running on fewer devices."""
+    with pytest.raises(ValueError, match="devices"):
+        hype_sharded_partition(
+            hg, 16, ShardedParams(seed=0, devices=_devices() + 1))
+
+
 def test_sharded_edge_cases():
     hg = Hypergraph.from_edge_lists(6, [[0, 1], [1, 2, 3], []])
     for k in (1, 2, 3, 8):

@@ -31,6 +31,28 @@ def test_superstep_complete_and_balanced(hg, k):
     assert sizes.max() - sizes.min() <= 1
 
 
+@pytest.mark.parametrize("method,handoffs", [
+    ("hype_superstep", 1),      # superstep -> batched
+    ("hype_device", 2),         # device -> superstep -> batched
+    ("hype_sharded", 2),        # sharded -> superstep -> batched
+])
+def test_engine_handoff_counts_fallbacks(monkeypatch, method, handoffs):
+    """With no device adjacency image (the hub-expansion guard tripped)
+    every device engine hands the run down the engine chain; each
+    hand-off must show in ``stats.fallbacks`` so a caller can tell the
+    engine it asked for from the one that ran."""
+    from repro.core.partition_api import _engine
+    hg = powerlaw_hypergraph(300, 200, seed=5, max_edge=20,
+                             max_degree=10)
+    monkeypatch.setattr(Hypergraph, "vertex_adjacency",
+                        lambda *a, **kw: None)
+    params_cls, runner = _engine(method)
+    a, st = runner(hg, 4, params_cls(seed=0), return_stats=True)
+    sizes = metrics.partition_sizes(a, 4)
+    assert sizes.max() - sizes.min() <= 1
+    assert st.fallbacks == handoffs
+
+
 def test_superstep_deterministic(hg):
     a1 = hype_superstep_partition(hg, 6, SuperstepParams(seed=3))
     a2 = hype_superstep_partition(hg, 6, SuperstepParams(seed=3))
