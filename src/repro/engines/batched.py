@@ -33,7 +33,6 @@ from __future__ import annotations
 
 import collections
 import dataclasses
-import time
 from typing import Optional
 
 import numpy as np
@@ -41,7 +40,8 @@ import numpy as np
 from ..core.hypergraph import Hypergraph
 from ..core import resilience
 from ..core import scoring
-from .runtime import BatchedStats, EngineRuntime, maybe_refine
+from .runtime import (BatchedStats, EngineRuntime, maybe_refine,
+                      partition_root, span)
 
 
 @dataclasses.dataclass
@@ -164,7 +164,6 @@ class BatchedState(EngineRuntime):
         pins, prow = scoring.gather_csr_rows(
             self.hg.e2v_indptr, self.hg.e2v_indices, edges)
         pins = pins.astype(np.int64)
-        self.stats.edges_scanned += pins.size
         unassigned = self.assignment[pins] < 0
         live = np.bincount(prow[unassigned], minlength=edges.size) > 0
         if not live.all():
@@ -196,6 +195,8 @@ class BatchedState(EngineRuntime):
         tile; dribbles below ``kernel_min`` rows are scored by the exact
         same formula on host, because a device round-trip per 2-3 rows is
         precisely the latency-bound pattern this engine exists to avoid.
+        The fringe upload, once a pass, and each kernel call, tile upload
+        and result read included, are the child span ``hype.refill.kernel``.
         """
         if cand.size == 0:
             return
@@ -208,7 +209,8 @@ class BatchedState(EngineRuntime):
             from repro.kernels.hype_score.ops import hype_scores
 
             plan = self.fault_plan
-            fringe_dev = jnp.asarray(self._fringe_buf)
+            with span(self, "hype.refill.kernel"):
+                fringe_dev = jnp.asarray(self._fringe_buf)
             for lo in range(0, miss.size, self.p.b):
                 chunk = miss[lo:lo + self.p.b]
                 # two B buckets (64 / b) keep retraces rare while small
@@ -222,9 +224,10 @@ class BatchedState(EngineRuntime):
                         self.hg, chunk, self.assignment,
                         cap_pins=self.p.cap_pins, pad_b=pad_b)
                 ordinal = self.stats.kernel_calls + 1
-                out = np.asarray(self._guarded_kernel(
-                    lambda: hype_scores(jnp.asarray(tile), fringe_dev),
-                    ordinal)).astype(np.float64)
+                with span(self, "hype.refill.kernel"):
+                    out = np.asarray(self._guarded_kernel(
+                        lambda: hype_scores(jnp.asarray(tile), fringe_dev),
+                        ordinal)).astype(np.float64)
                 if plan is not None:
                     sp = plan.fire(("nan",), ordinal)
                     if sp is not None:    # poison the whole score tile
@@ -322,11 +325,14 @@ def _grow_partition(st: BatchedState, phase: int, target: int,
         # ------- refill: bulk-draw and kernel-score new candidates -------
         if pool.size < max(p.t, p.refill_lo):
             if pending:
-                st.activate(np.concatenate(pending), phase)
+                with span(st, "hype.refill.activate"):
+                    st.activate(np.concatenate(pending), phase)
                 pending = []
-            cand = st.draw_candidates(p.pool_cap - pool.size)
+            with span(st, "hype.refill.draw"):
+                cand = st.draw_candidates(p.pool_cap - pool.size)
             if cand.size:
-                st.score_misses(cand)
+                with span(st, "hype.refill.score"):
+                    st.score_misses(cand)
                 st.in_pool[cand] = True
                 pool = np.concatenate([pool, cand])
                 pool = pool[np.argsort(st.cache[pool], kind="stable")]
@@ -337,7 +343,6 @@ def _grow_partition(st: BatchedState, phase: int, target: int,
             vs = st.random_unassigned(p.t)
             if vs.size == 0:
                 return
-            st.stats.random_restarts += 1
             pool = vs
             st.in_pool[vs] = True
             st.cache[vs] = 0.0
@@ -356,6 +361,7 @@ def _grow_partition(st: BatchedState, phase: int, target: int,
     st.in_pool[pool] = False
 
 
+@partition_root
 def hype_batched_partition(hg: Hypergraph, k: int,
                            params: Optional[BatchedParams] = None,
                            return_stats: bool = False):
@@ -395,52 +401,49 @@ def hype_batched_partition(hg: Hypergraph, k: int,
     ckpt = (resilience.load_latest(params.resume) if params.resume
             else None)
     if ckpt is not None:
-        t0 = time.perf_counter()
-        resilience.check_checkpoint(ckpt, hg, k)
-        if ckpt.engine == "hype_batched" and ckpt.config == config:
-            pay = ckpt.payload
-            st.assignment = pay["assignment"].copy()
-            st.edge_dead = pay["edge_dead"].copy()
-            st.edge_epoch = pay["edge_epoch"].copy()
-            st.rand_ptr = int(pay["rand_ptr"])
-            st.rng.bit_generator.state = pay["rng_state"]
-            st.stats = dataclasses.replace(pay["stats"])
-            start = int(pay["next_phase"])
-        else:
-            wa = resilience.warm_assignment(ckpt)
-            got = wa >= 0
-            st.assignment[got] = wa[got]
-            warm = True
-        st.stats.resumed_at = int(ckpt.superstep)
-        st.stats.restore_s += time.perf_counter() - t0
+        with span(st, "hype.restore"):
+            resilience.check_checkpoint(ckpt, hg, k)
+            if ckpt.engine == "hype_batched" and ckpt.config == config:
+                pay = ckpt.payload
+                st.assignment = pay["assignment"].copy()
+                st.edge_dead = pay["edge_dead"].copy()
+                st.edge_epoch = pay["edge_epoch"].copy()
+                st.rand_ptr = int(pay["rand_ptr"])
+                st.rng.bit_generator.state = pay["rng_state"]
+                st.stats = pay["stats"].copy()
+                start = int(pay["next_phase"])
+            else:
+                wa = resilience.warm_assignment(ckpt)
+                got = wa >= 0
+                st.assignment[got] = wa[got]
+                warm = True
+            st.stats.resumed_at = int(ckpt.superstep)
     last_snap = start
-    for i in range(start, k):
-        if i == k - 1:
-            rem_v = np.flatnonzero(st.assignment < 0)
-            st.assignment[rem_v] = i
-            st.in_fringe[:] = False
-            break
-        _grow_partition(st, i, base + (1 if i < rem else 0), warm=warm)
+    for i in range(start, k - 1):
+        with span(st, "hype.grow"):
+            _grow_partition(st, i, base + (1 if i < rem else 0), warm=warm)
         if snap_every and i + 1 - last_snap >= snap_every:
-            t0 = time.perf_counter()
-            st.stats.snapshots += 1
-            resilience.save_snapshot(
-                params.snapshot_dir,
-                resilience.PartitionCheckpoint(
-                    "hype_batched", i + 1, hg.fingerprint(),
-                    dict(config),
-                    {"assignment": st.assignment.copy(),
-                     "edge_dead": st.edge_dead.copy(),
-                     "edge_epoch": st.edge_epoch.copy(),
-                     "rand_ptr": int(st.rand_ptr),
-                     "rng_state": st.rng.bit_generator.state,
-                     "stats": dataclasses.replace(st.stats),
-                     "next_phase": i + 1}),
-                keep_last=int(params.keep_last))
-            st.stats.snapshot_s += time.perf_counter() - t0
+            with span(st, "hype.snapshot"):
+                st.stats.snapshots += 1
+                resilience.save_snapshot(
+                    params.snapshot_dir,
+                    resilience.PartitionCheckpoint(
+                        "hype_batched", i + 1, hg.fingerprint(),
+                        dict(config),
+                        {"assignment": st.assignment.copy(),
+                         "edge_dead": st.edge_dead.copy(),
+                         "edge_epoch": st.edge_epoch.copy(),
+                         "rand_ptr": int(st.rand_ptr),
+                         "rng_state": st.rng.bit_generator.state,
+                         "stats": st.stats.copy(),
+                         "next_phase": i + 1}),
+                    keep_last=int(params.keep_last))
             last_snap = i + 1
-    assert (st.assignment >= 0).all()
-    assignment = maybe_refine(hg, k, params, st.assignment, st.stats)
+    with span(st, "hype.finish"):
+        # the last phase takes every vertex still unassigned
+        st.assignment[st.assignment < 0] = k - 1
+        st.in_fringe[:] = False
+        assignment = maybe_refine(hg, k, params, st.assignment, st.stats)
     if return_stats:
         return assignment, st.stats
     return assignment

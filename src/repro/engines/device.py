@@ -30,7 +30,7 @@ from ..core import membudget
 from ..core import resilience
 from ..core.scoring import gather_csr_rows
 from .pipeline import PipelineState
-from .runtime import BatchedStats, maybe_refine
+from .runtime import BatchedStats, maybe_refine, span
 from .superstep import SuperstepParams
 
 
@@ -172,8 +172,6 @@ def _device_attempt(hg: Hypergraph, k: int, p: DeviceParams,
     or ``("overflow", flags, caps)``. DeviceOOM propagates (enriched
     with rung + partial) for the caller's ladder.
     """
-    import time as _time
-
     chunk_max = max(1, int(getattr(p, "chunk_supersteps", 64)))
     cache_dtype = str(getattr(p, "cache_dtype", "float32"))
     cache_f16 = cache_dtype == "float16"
@@ -214,20 +212,19 @@ def _device_attempt(hg: Hypergraph, k: int, p: DeviceParams,
     resumed_carry = None
     ckpt = resilience.load_latest(p.resume) if p.resume else None
     if ckpt is not None:
-        t0 = _time.perf_counter()
-        resilience.check_checkpoint(ckpt, hg, k)
-        if ckpt.engine == engine and ckpt.config == config:
-            pay = ckpt.payload
-            resumed_carry = {kk: vv.copy()
-                             for kk, vv in pay["carry"].items()}
-            caps = dict(pay["caps"])
-            caps.update(caps_over)
-            st.stats = dataclasses.replace(pay["stats"])
-            acc = np.asarray(resumed_carry["acc"], dtype=np.int64)
-        else:
-            acc = st.restore_warm(resilience.warm_assignment(ckpt))
-        st.stats.resumed_at = int(ckpt.superstep)
-        st.stats.restore_s += _time.perf_counter() - t0
+        with span(st, "hype.restore"):
+            resilience.check_checkpoint(ckpt, hg, k)
+            if ckpt.engine == engine and ckpt.config == config:
+                pay = ckpt.payload
+                resumed_carry = {kk: vv.copy()
+                                 for kk, vv in pay["carry"].items()}
+                caps = dict(pay["caps"])
+                caps.update(caps_over)
+                st.stats = pay["stats"].copy()
+                acc = np.asarray(resumed_carry["acc"], dtype=np.int64)
+            else:
+                acc = st.restore_warm(resilience.warm_assignment(ckpt))
+            st.stats.resumed_at = int(ckpt.superstep)
 
     if resumed_carry is None:
         # seed every empty phase with one random vertex — exactly the
@@ -294,54 +291,51 @@ def _device_attempt(hg: Hypergraph, k: int, p: DeviceParams,
         return {"carry": {kk: np.asarray(vv)
                           for kk, vv in carry_dev.items()},
                 "caps": dict(caps),
-                "stats": dataclasses.replace(st.stats)}
+                "stats": st.stats.copy()}
 
     last_snap = int(carry_np["supersteps"])
     last_known = st.assignment.copy()
-    t_wall0 = _time.perf_counter()
-    host_accum = 0.0
     try:
         while True:
-            t_host = _time.perf_counter()
-            ss_now = int(np.asarray(carry["supersteps"]))
-            acc_h = np.asarray(carry["acc"]).astype(np.int64)
-            if snap_every and ss_now - last_snap >= snap_every:
-                t0 = _time.perf_counter()
-                st.stats.snapshots += 1
-                resilience.save_snapshot(
-                    p.snapshot_dir,
-                    resilience.PartitionCheckpoint(
-                        engine, ss_now, hg.fingerprint(), dict(config),
-                        _snapshot_payload(carry)),
-                    keep_last=int(p.keep_last))
-                st.stats.snapshot_s += _time.perf_counter() - t0
-                last_snap = ss_now
-                last_known = np.asarray(carry["assign"]).copy()
-            if (acc_h >= targets).all():
-                break
-            if int(np.asarray(carry["progress"])) == 0:
-                break   # starved: stragglers sit in other pools
-            cap = chunk_max
-            if snap_every:
-                cap = min(cap, snap_every - (ss_now - last_snap))
-            cap = max(1, cap)
-            _device_probe_faults(st, ss_now + 1, ss_now + cap)
-            poison_at = _device_probe_nan(st, ss_now + 1, ss_now + cap)
-            if poison_at > 0:
-                cap = poison_at - ss_now    # poisoned step ends chunk
-            host_accum += _time.perf_counter() - t_host
-            t_dev = _time.perf_counter()
-            try:
-                carry = run(consts, carry, jnp.int32(cap),
-                            jnp.int32(poison_at))
-                flags = int(np.asarray(carry["flags"]))   # blocks
-            except Exception as exc:
-                if membudget.is_oom_error(exc):
-                    raise membudget.DeviceOOM(
-                        f"device loop chunk failed: {exc!r}",
-                        rung=st.mem_rung) from exc
-                raise
-            st.stats.device_s += _time.perf_counter() - t_dev
+            # the host's turn: read the carry, snapshot, probe faults
+            with span(st, "hype.superstep.dispatch"):
+                ss_now = int(np.asarray(carry["supersteps"]))
+                acc_h = np.asarray(carry["acc"]).astype(np.int64)
+                if snap_every and ss_now - last_snap >= snap_every:
+                    with span(st, "hype.snapshot"):
+                        st.stats.snapshots += 1
+                        resilience.save_snapshot(
+                            p.snapshot_dir,
+                            resilience.PartitionCheckpoint(
+                                engine, ss_now, hg.fingerprint(),
+                                dict(config), _snapshot_payload(carry)),
+                            keep_last=int(p.keep_last))
+                    last_snap = ss_now
+                    last_known = np.asarray(carry["assign"]).copy()
+                if (acc_h >= targets).all():
+                    break
+                if int(np.asarray(carry["progress"])) == 0:
+                    break   # starved: stragglers sit in other pools
+                cap = chunk_max
+                if snap_every:
+                    cap = min(cap, snap_every - (ss_now - last_snap))
+                cap = max(1, cap)
+                _device_probe_faults(st, ss_now + 1, ss_now + cap)
+                poison_at = _device_probe_nan(st, ss_now + 1,
+                                              ss_now + cap)
+                if poison_at > 0:
+                    cap = poison_at - ss_now    # poisoned step ends chunk
+            with span(st, "hype.superstep.wait"):
+                try:
+                    carry = run(consts, carry, jnp.int32(cap),
+                                jnp.int32(poison_at))
+                    flags = int(np.asarray(carry["flags"]))   # blocks
+                except Exception as exc:
+                    if membudget.is_oom_error(exc):
+                        raise membudget.DeviceOOM(
+                            f"device loop chunk failed: {exc!r}",
+                            rung=st.mem_rung) from exc
+                    raise
             st.stats.loop_chunks += 1
             if flags:
                 if flags & device_loop.FLAG_POISON:
@@ -355,7 +349,6 @@ def _device_attempt(hg: Hypergraph, k: int, p: DeviceParams,
             exc.rung = int(st.mem_plan.rung)
         exc.partial = last_known
         raise
-    st.stats.host_s += host_accum
 
     # final download + host mirror
     st.assignment = np.asarray(carry["assign"]).astype(np.int32,
@@ -371,10 +364,8 @@ def _device_attempt(hg: Hypergraph, k: int, p: DeviceParams,
         int(dstats[device_loop.S_STORE_PEAK]))
     st.stats.refill_signals += int(dstats[device_loop.S_REFILL])
     st.stats.kernel_rows += int(dstats[device_loop.S_KERNEL_ROWS])
-    st.stats.edges_scanned += int(dstats[device_loop.S_EDGES_SCANNED])
     st.stats.cache_invalidations += int(dstats[device_loop.S_CACHE_INV])
     st.stats.cache_hits += int(dstats[device_loop.S_CACHE_HITS])
-    st.stats.random_restarts += int(dstats[device_loop.S_RESTARTS])
     st.stats.stale_redraws += int(dstats[device_loop.S_STALE])
     st.stats.retries += int(dstats[device_loop.S_RETRIES])
     # safety net: balance-fill any stragglers into underfull phases
@@ -387,7 +378,6 @@ def _device_attempt(hg: Hypergraph, k: int, p: DeviceParams,
     obs = membudget.observed_peak_bytes()
     st.stats.peak_bytes_observed = (int(obs) if obs else
                                     int(st.stats.peak_bytes_planned))
-    del t_wall0
     return ("ok", st.assignment, st)
 
 

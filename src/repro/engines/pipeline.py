@@ -25,7 +25,7 @@ from ..core.hypergraph import Hypergraph
 from ..core import membudget
 from ..core import resilience
 from ..core import scoring
-from .runtime import EngineRuntime, SnapshotMixin, _RESET0, _RESET1
+from .runtime import EngineRuntime, SnapshotMixin, _RESET0, _RESET1, span
 
 # Flat bucket-store key layout: one sorted int64 per queued (phase,
 # class, edge) activation — phase in the top bits, the power-of-two
@@ -168,40 +168,45 @@ class PipelineState(SnapshotMixin, EngineRuntime):
         import jax.numpy as jnp
 
         n, m = hg.n, hg.m
-        try:
-            if plan.paged:
-                # no resident CSR: the pager uploads id-range chunks on
-                # demand under its own LRU byte budget. ``dev`` keeps a
-                # non-None sentinel so the driver takes the device path.
-                self.paged_adj = membudget.PagedAdjacency(
-                    self.adj, plan.page_bytes, self.stats)
-                self.dev = (None, None)
-            else:
-                self.dev = hg.device_adjacency(mesh=mesh)
-                if self.dev is None:
-                    return
-            self.dev_assign = jnp.full((n,), -1, jnp.int32)
-            if plan.spill_cache:
-                self.host_cache = np.full(n, -1.0, dtype=np.float32)
-            else:
-                self.dev_cache = jnp.full((n,), -1.0, jnp.float32)
-            self.dev_acc = jnp.zeros((k,), jnp.int32)
-            # sticky NaN-quarantine flag (scoring._poison_guard), donated
-            # through every superstep like the rest of the mutable image
-            self.dev_poison = jnp.zeros((1,), jnp.int32)
-        except Exception as exc:
-            if membudget.is_oom_error(exc):
-                raise membudget.DeviceOOM(
-                    f"device image upload failed: {exc!r}",
-                    rung=self.mem_rung) from exc
-            raise
-        if mesh is not None:       # replicate the mutable image too
-            from jax.sharding import NamedSharding, PartitionSpec
-            rep = NamedSharding(mesh, PartitionSpec())
-            self.dev_assign = jax.device_put(self.dev_assign, rep)
-            self.dev_cache = jax.device_put(self.dev_cache, rep)
-            self.dev_acc = jax.device_put(self.dev_acc, rep)
-            self.dev_poison = jax.device_put(self.dev_poison, rep)
+        # times the upload's issue only: the transfer runs on and lands
+        # in the first superstep's ``hype.superstep.wait``
+        with span(self, "hype.setup.image"):
+            try:
+                if plan.paged:
+                    # no resident CSR: the pager uploads id-range chunks
+                    # on demand under its own LRU byte budget. ``dev``
+                    # keeps a non-None sentinel so ``run_pipeline`` takes the
+                    # device path.
+                    self.paged_adj = membudget.PagedAdjacency(
+                        self.adj, plan.page_bytes, self.stats)
+                    self.dev = (None, None)
+                else:
+                    self.dev = hg.device_adjacency(mesh=mesh)
+                    if self.dev is None:
+                        return
+                self.dev_assign = jnp.full((n,), -1, jnp.int32)
+                if plan.spill_cache:
+                    self.host_cache = np.full(n, -1.0, dtype=np.float32)
+                else:
+                    self.dev_cache = jnp.full((n,), -1.0, jnp.float32)
+                self.dev_acc = jnp.zeros((k,), jnp.int32)
+                # sticky NaN-quarantine flag (scoring._poison_guard),
+                # donated through every superstep like the rest of the
+                # mutable image
+                self.dev_poison = jnp.zeros((1,), jnp.int32)
+            except Exception as exc:
+                if membudget.is_oom_error(exc):
+                    raise membudget.DeviceOOM(
+                        f"device image upload failed: {exc!r}",
+                        rung=self.mem_rung) from exc
+                raise
+            if mesh is not None:       # replicate the mutable image too
+                from jax.sharding import NamedSharding, PartitionSpec
+                rep = NamedSharding(mesh, PartitionSpec())
+                self.dev_assign = jax.device_put(self.dev_assign, rep)
+                self.dev_cache = jax.device_put(self.dev_cache, rep)
+                self.dev_acc = jax.device_put(self.dev_acc, rep)
+                self.dev_poison = jax.device_put(self.dev_poison, rep)
         self.cache_scored = np.zeros(n, dtype=bool)
         self.pools = [np.empty(0, dtype=np.int64) for _ in range(k)]
         # flat (phase, class, edge) bucket store — two parallel arrays
@@ -516,7 +521,6 @@ class PipelineState(SnapshotMixin, EngineRuntime):
         pins, prow = scoring.gather_csr_rows(
             self.hg.e2v_indptr, self.hg.e2v_indices, edges_t)
         pins = pins.astype(np.int64)
-        self.stats.edges_scanned += int(pins.size)
         edge_lo = np.searchsorted(ph_t, np.arange(kG + 1, dtype=np.int64))
         pin_lo = np.searchsorted(prow, edge_lo)
         # per-phase first-occurrence dedup of the pin streams. The
@@ -583,7 +587,6 @@ class PipelineState(SnapshotMixin, EngineRuntime):
                 vs = self.random_unassigned(
                     min(t, int(targets[gi] - acc[gi])), in_pool=rmask)
                 if vs.size:
-                    self.stats.random_restarts += 1
                     self.assign_now(vs, gi)
                     self.activate_phase(vs, gi)
                     acc[gi] += vs.size
@@ -736,8 +739,11 @@ class PipelineState(SnapshotMixin, EngineRuntime):
         return nh
 
     def harvest(self, handle, acc: np.ndarray, targets: np.ndarray,
-                exclude=()) -> int:
+                exclude=()) -> tuple[int, bool]:
         """Block on one in-flight superstep and mirror its admissions.
+
+        Returns the admissions mirrored and whether the handle was
+        quarantined and replayed.
 
         The only blocking transfer of the steady state: everything else
         the driver does (packing superstep N+1) happens while the device
@@ -752,39 +758,47 @@ class PipelineState(SnapshotMixin, EngineRuntime):
         which reverted itself on device) is replayed from its clean
         args before mirroring — direct dispatch/harvest callers survive
         an injected NaN tile without the pipeline driver's help; the
-        driver additionally replays the whole in-flight window to keep
+        driver then replays the rest of its in-flight window to keep
         device-effect order (see ``runtime._harvest_next``).
-        """
-        import time as _time
 
-        if int(np.asarray(handle.poison)[0]) > 0:
-            handle = self.replay(handle)
-        winners_dev, stale_dev = handle.winners, handle.n_stale
+        Two spans: ``hype.superstep.wait`` from the first blocking read
+        (the poison flag) until the results are on the host, then
+        ``hype.superstep.mirror``.
+        """
+        with span(self, "hype.superstep.wait"):
+            replayed = int(np.asarray(handle.poison)[0]) > 0
+            if replayed:
+                handle = self.replay(handle)
+            try:
+                winners = np.asarray(handle.winners)
+                n_stale = int(handle.n_stale)
+                if (self.host_cache is not None
+                        and handle.scores is not None):
+                    # spill rung: adopt the fresh scores into the host
+                    # mirror — the same pad-dropping scatter the device
+                    # cache write performs, after the poison check above
+                    flat = handle.args.fresh.reshape(-1)
+                    sc = np.asarray(handle.scores).reshape(-1)
+                    real = flat >= 0
+                    self.host_cache[flat[real].astype(np.int64)] = sc[real]
+            except membudget.DeviceOOM:
+                raise
+            except Exception as exc:
+                # a real allocator failure can surface at the blocking
+                # transfer, not just at dispatch — same recovery path
+                if membudget.is_oom_error(exc):
+                    raise membudget.DeviceOOM(
+                        f"superstep harvest failed: {exc!r}",
+                        rung=self.mem_rung) from exc
+                raise
+        with span(self, "hype.superstep.mirror"):
+            return self._mirror(handle, winners, n_stale, acc, targets,
+                                exclude), replayed
+
+    def _mirror(self, handle, winners: np.ndarray, n_stale: int,
+                acc: np.ndarray, targets: np.ndarray, exclude) -> int:
+        """Mirror a harvested superstep's admissions on the host."""
         fresh_ids = handle.fresh_ids
-        t0 = _time.perf_counter()
-        try:
-            winners = np.asarray(winners_dev)
-            n_stale = int(stale_dev)
-            if self.host_cache is not None and handle.scores is not None:
-                # spill rung: adopt the fresh scores into the host
-                # mirror — the same pad-dropping scatter the device
-                # cache write performs, after the poison check above
-                flat = handle.args.fresh.reshape(-1)
-                sc = np.asarray(handle.scores).reshape(-1)
-                real = flat >= 0
-                self.host_cache[flat[real].astype(np.int64)] = sc[real]
-        except membudget.DeviceOOM:
-            raise
-        except Exception as exc:
-            # a real allocator failure can surface at the blocking
-            # transfer, not just at dispatch — same recovery path
-            if membudget.is_oom_error(exc):
-                raise membudget.DeviceOOM(
-                    f"superstep harvest failed: {exc!r}",
-                    rung=self.mem_rung) from exc
-            raise
-        self.stats.device_s += _time.perf_counter() - t0
-        t0 = _time.perf_counter()
         self.stats.stale_redraws += n_stale
         if fresh_ids.size:
             self.cache_scored[fresh_ids] = True
@@ -806,7 +820,6 @@ class PipelineState(SnapshotMixin, EngineRuntime):
                     self._pmask(gi)[self.pools[gi]] = False
                     self.pools[gi] = np.empty(0, dtype=np.int64)
         self._count_harvest(handle)
-        self.stats.host_s += _time.perf_counter() - t0
         return progress
 
     def _release_members(self, vs: np.ndarray, ph: np.ndarray) -> None:

@@ -10,6 +10,9 @@ once:
     core/resilience.py §4f), and the compile-cache placement.
   * ``BatchedStats`` — the family-wide counter dataclass, plus ``merge``
     for combining the stats of split or restarted runs.
+  * ``span`` / ``partition_root`` — named host spans: each
+    adds its wall time to ``BatchedStats.spans`` and opens a profiler
+    ``TraceAnnotation`` carrying the partition call's id.
   * ``SnapshotMixin`` — snapshot capture / exact restore / cross-engine
     warm start for the device-image engines (§4f cadence semantics).
   * ``run_pipeline`` / ``run_pipeline_budgeted`` — the double-buffered
@@ -25,11 +28,15 @@ never import each other's internals (enforced by
 from __future__ import annotations
 
 import collections
+import contextvars
 import dataclasses
+import functools
+import itertools
 import time
 from typing import Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..core.hypergraph import Hypergraph
 from ..core import membudget
@@ -43,14 +50,22 @@ _RESET0 = np.zeros(1, dtype=np.int32)
 _RESET1 = np.ones(1, dtype=np.int32)
 
 
+# the spans the pipeline counters are read from
+_HOST_SPANS = ("hype.superstep.pack", "hype.superstep.dispatch",
+               "hype.superstep.mirror")
+_WAIT_SPAN = "hype.superstep.wait"
+
+# id of the partition() call whose spans are open; 0 outside any call
+_CALL = contextvars.ContextVar("hype_call", default=0)
+_CALL_IDS = itertools.count(1)
+
+
 @dataclasses.dataclass
 class BatchedStats:
     kernel_calls: int = 0
     kernel_rows: int = 0       # candidate rows scored by the Pallas kernel
     host_rows: int = 0         # rows scored by the numpy fallback
     cache_hits: int = 0
-    edges_scanned: int = 0     # pins scanned during candidate selection
-    random_restarts: int = 0
     steps: int = 0
     # superstep-engine counters (zero for the classic batched path):
     supersteps: int = 0             # fused device calls
@@ -65,11 +80,8 @@ class BatchedStats:
     #                                 devices x global payload per superstep
     admission_conflicts: int = 0    # proposed admissions lost to the
     #                                 lowest-phase-wins conflict rule
-    # pipeline counters (superstep/sharded engines):
-    host_s: float = 0.0             # wall-clock spent in host packing +
-    #                                 harvest mirroring (overlappable)
-    device_s: float = 0.0           # wall-clock blocked waiting on device
-    #                                 results at harvest time
+    # pipeline counters (superstep/sharded engines; ``host_s`` and
+    # ``device_s`` are read from ``spans``, below):
     pipeline_stalls: int = 0        # rounds where the host could pack
     #                                 nothing and the device went idle
     stale_redraws: int = 0          # pool slots skipped on device because
@@ -94,8 +106,6 @@ class BatchedStats:
     #                                 ran (partition_resilient) plus
     #                                 in-engine fallbacks to a lower one
     snapshots: int = 0              # checkpoints published
-    snapshot_s: float = 0.0         # wall-clock publishing checkpoints
-    restore_s: float = 0.0          # wall-clock restoring the resume ckpt
     resumed_at: int = -1            # superstep/phase the run resumed
     #                                 from; -1 = fresh start
     # memory-budget counters (core/membudget.py, DESIGN.md §4g):
@@ -114,6 +124,8 @@ class BatchedStats:
     page_bytes: int = 0             # total bytes uploaded by the pager
     # refinement post-pass (None unless refine_passes > 0 ran):
     refine: Optional[object] = None     # core.refine.RefineStats
+    # named host spans (``span``): name -> [inclusive wall seconds, count]
+    spans: dict = dataclasses.field(default_factory=dict)
 
     # counters that are high-water marks / identities rather than sums —
     # ``merge`` keeps the max (or the non-default value) instead of adding
@@ -126,19 +138,120 @@ class BatchedStats:
 
         Additive counters sum; peak/identity fields keep the max; the
         ``refine`` record of the later run wins (the earlier one refined
-        an assignment that no longer exists). Used when a partition is
-        assembled from multiple engine runs (restarts, split ladders).
+        an assignment that no longer exists); span totals and counts sum
+        by name. Used when a partition is assembled from multiple engine
+        runs (restarts, split ladders).
         """
         out = BatchedStats()
         for f in dataclasses.fields(BatchedStats):
             a, b = getattr(self, f.name), getattr(other, f.name)
             if f.name == "refine":
                 out.refine = b if b is not None else a
+            elif f.name == "spans":
+                out.spans = {n: list(r) for n, r in a.items()}
+                for name, (sec, count) in b.items():
+                    out.add_span(name, sec, count)
             elif f.name in self._MERGE_MAX:
                 setattr(out, f.name, max(a, b))
             else:
                 setattr(out, f.name, a + b)
         return out
+
+    def copy(self) -> "BatchedStats":
+        """An independent copy (snapshots hold one; spans are mutable)."""
+        return dataclasses.replace(
+            self, spans={n: list(r) for n, r in self.spans.items()})
+
+    def add_span(self, name: str, seconds: float, count: int = 1) -> None:
+        rec = self.spans.get(name)
+        if rec is None:
+            self.spans[name] = [seconds, count]
+        else:
+            rec[0] += seconds
+            rec[1] += count
+
+    def span_s(self, *names: str) -> float:
+        """Summed inclusive seconds of the named spans."""
+        return sum(self.spans[n][0] for n in names if n in self.spans)
+
+    @property
+    def host_s(self) -> float:
+        """Host packing, dispatch and harvest mirroring (overlappable)."""
+        return self.span_s(*_HOST_SPANS)
+
+    @property
+    def device_s(self) -> float:
+        """Blocked at harvest waiting on the device's results."""
+        return self.span_s(_WAIT_SPAN)
+
+    @property
+    def snapshot_s(self) -> float:
+        """Publishing checkpoints."""
+        return self.span_s("hype.snapshot")
+
+    @property
+    def restore_s(self) -> float:
+        """Restoring the resume checkpoint."""
+        return self.span_s("hype.restore")
+
+
+class span:
+    """A named host span: ``with span(state, "hype.superstep.pack"):``.
+
+    Adds the block's inclusive wall time and a count to
+    ``state.stats.spans[name]``, with ``state.stats`` read as the block
+    closes, so a restore that swaps the stats object inside the block
+    lands its time on the restored one. While a profiler records, the
+    block also runs inside a ``jax.profiler.TraceAnnotation`` carrying
+    the id of the enclosing ``partition_root`` call, so the trace holds
+    the span with its start, end and parent; otherwise no annotation is
+    made. Only totals are kept; the profiler keeps the events.
+    """
+    __slots__ = ("owner", "name", "ann", "t0")
+
+    def __init__(self, owner, name: str):
+        self.owner = owner
+        self.name = name
+        self.ann = None
+
+    def __enter__(self):
+        if TraceAnnotation.is_enabled():
+            self.ann = TraceAnnotation(self.name, call=_CALL.get())
+            self.ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        dt = time.perf_counter() - self.t0
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
+        self.owner.stats.add_span(self.name, dt)
+
+
+def partition_root(runner):
+    """Run an engine runner inside the call's root span, ``hype.partition``.
+
+    Draws the call id that every span of the call carries and adds the
+    call's wall time to the stats the runner returns. A runner called
+    inside another's root (superstep handing off to batched) runs as
+    part of that call, under its root. The signature stays the runners'
+    ``(hg, k, params=None, return_stats=False)``.
+    """
+    @functools.wraps(runner)
+    def run(hg, k, params=None, return_stats=False):
+        if _CALL.get():
+            return runner(hg, k, params, return_stats)
+        token = _CALL.set(next(_CALL_IDS))
+        try:
+            t0 = time.perf_counter()
+            with TraceAnnotation("hype.partition", call=_CALL.get()):
+                assignment, stats = runner(hg, k, params,
+                                           return_stats=True)
+            stats.add_span("hype.partition", time.perf_counter() - t0)
+        finally:
+            _CALL.reset(token)
+        return (assignment, stats) if return_stats else assignment
+    return run
 
 
 class EngineRuntime:
@@ -171,7 +284,8 @@ class EngineRuntime:
         # One-time unique-neighbor CSR (memoized on hg): turns every tile
         # build into a pure gather. None for pathological hub expansions —
         # scoring then falls back to per-batch dedup with cap_pins.
-        self.adj = hg.vertex_adjacency()
+        with span(self, "hype.setup.adjacency"):
+            self.adj = hg.vertex_adjacency()
         # deterministic fault schedule: the param (shared instance across
         # a degradation ladder) or a FRESH parse of REPRO_FAULT_PLAN per
         # engine run, so every run of a chaos suite sees the full plan
@@ -317,7 +431,7 @@ class SnapshotMixin:
             "rand_ptr": int(self.rand_ptr),
             "rng_state": self.rng.bit_generator.state,
             "dirty_ratchet": int(self._dirty_ratchet),
-            "stats": dataclasses.replace(self.stats),
+            "stats": self.stats.copy(),
             "dev_assign": np.asarray(self.dev_assign),
             # on the spill rung the authoritative cache IS the host
             # mirror; either way the payload carries plain numpy
@@ -352,7 +466,7 @@ class SnapshotMixin:
         self.rand_ptr = int(pay["rand_ptr"])
         self.rng.bit_generator.state = pay["rng_state"]
         self._dirty_ratchet = int(pay["dirty_ratchet"])
-        self.stats = dataclasses.replace(pay["stats"])
+        self.stats = pay["stats"].copy()
         self.dev_assign = self._to_device(pay["dev_assign"])
         if self.host_cache is not None:
             self.host_cache = pay["dev_cache"].astype(np.float32,
@@ -413,19 +527,24 @@ def _harvest_next(st, inflight: collections.deque,
     """Harvest the oldest in-flight superstep, replaying a poisoned one.
 
     When the popped superstep was quarantined (non-finite scores — an
-    injected NaN tile, normally), every in-flight superstep dispatched
-    after it self-aborted on the sticky poison flag: replay the whole
-    window in FIFO order from the handles' clean args so device-effect
-    order — and therefore bit-identical recovery — is preserved.
+    injected NaN tile, normally), ``harvest`` replays it, and every
+    in-flight superstep dispatched after it self-aborted on the sticky
+    poison flag: replay the whole window in FIFO order from the handles'
+    clean args so device-effect order — and therefore bit-identical
+    recovery — is preserved. Mirroring touches only host state, so the
+    window may replay after it. The window's replay is one more
+    ``hype.superstep.wait`` span.
     """
     h = inflight.popleft()
-    if int(np.asarray(h.poison)[0]) > 0:
-        h = st.replay(h)
-        redo = list(inflight)
-        inflight.clear()
-        for old in redo:
-            inflight.append(st.replay(old))
-    return st.harvest(h, acc, targets, [e.fresh_ids for e in inflight])
+    progress, replayed = st.harvest(h, acc, targets,
+                                    [e.fresh_ids for e in inflight])
+    if replayed and inflight:
+        with span(st, "hype.superstep.wait"):
+            redo = list(inflight)
+            inflight.clear()
+            for old in redo:
+                inflight.append(st.replay(old))
+    return progress
 
 
 def _teardown_pipeline(st, inflight: collections.deque) -> None:
@@ -480,8 +599,6 @@ def run_pipeline(hg: Hypergraph, k: int, p, make_state, engine: str,
     with the same cadence (a cross-engine snapshot warm-starts from its
     assignment instead). Any exception tears the pipeline down safely.
     """
-    import time as _time
-
     st = make_state(p, mem_rung)
     if st.dev is None:
         return None, None                       # caller falls back
@@ -518,15 +635,14 @@ def run_pipeline(hg: Hypergraph, k: int, p, make_state, engine: str,
     seeded = False
     ckpt = resilience.load_latest(p.resume) if p.resume else None
     if ckpt is not None:
-        t0 = _time.perf_counter()
-        resilience.check_checkpoint(ckpt, hg, k)
-        if ckpt.engine == engine and ckpt.config == config:
-            acc, cur_depth = st.restore_exact(ckpt.payload)
-            seeded = True       # the snapshot already carries the seeds
-        else:
-            acc = st.restore_warm(resilience.warm_assignment(ckpt))
-        st.stats.resumed_at = int(ckpt.superstep)
-        st.stats.restore_s += _time.perf_counter() - t0
+        with span(st, "hype.restore"):
+            resilience.check_checkpoint(ckpt, hg, k)
+            if ckpt.engine == engine and ckpt.config == config:
+                acc, cur_depth = st.restore_exact(ckpt.payload)
+                seeded = True   # the snapshot already carries the seeds
+            else:
+                acc = st.restore_warm(resilience.warm_assignment(ckpt))
+            st.stats.resumed_at = int(ckpt.superstep)
     elif mem_warm is not None:
         # memory-rung retry (DESIGN.md §4g): adopt the failed attempt's
         # host assignment mirror so already-grown members survive the
@@ -551,57 +667,55 @@ def run_pipeline(hg: Hypergraph, k: int, p, make_state, engine: str,
     last_snap = int(st.stats.supersteps)
     inflight: collections.deque = collections.deque()
     try:
-        while True:
-            progress = 0
-            if (snap_every
-                    and st.stats.supersteps - last_snap >= snap_every):
-                while inflight:     # drain: snapshots see settled state
+        with span(st, "hype.grow"):
+            while True:
+                progress = 0
+                if (snap_every
+                        and st.stats.supersteps - last_snap >= snap_every):
+                    while inflight:     # drain: snapshots see settled state
+                        progress += _harvest_next(st, inflight, acc, targets)
+                    with span(st, "hype.snapshot"):
+                        st.stats.snapshots += 1
+                        resilience.save_snapshot(
+                            p.snapshot_dir,
+                            resilience.PartitionCheckpoint(
+                                engine, int(st.stats.supersteps),
+                                hg.fingerprint(), dict(config),
+                                st.capture_payload(acc, cur_depth)),
+                            keep_last=int(p.keep_last))
+                    last_snap = int(st.stats.supersteps)
+                active = np.flatnonzero(acc < targets)
+                if active.size == 0:
+                    break
+                while len(inflight) >= cur_depth:   # tail heuristic shrank
                     progress += _harvest_next(st, inflight, acc, targets)
-                t0 = _time.perf_counter()
-                st.stats.snapshots += 1
-                resilience.save_snapshot(
-                    p.snapshot_dir,
-                    resilience.PartitionCheckpoint(
-                        engine, int(st.stats.supersteps),
-                        hg.fingerprint(), dict(config),
-                        st.capture_payload(acc, cur_depth)),
-                    keep_last=int(p.keep_last))
-                st.stats.snapshot_s += _time.perf_counter() - t0
-                last_snap = int(st.stats.supersteps)
-            active = np.flatnonzero(acc < targets)
-            if active.size == 0:
-                break
-            while len(inflight) >= cur_depth:   # tail heuristic shrank
-                progress += _harvest_next(st, inflight, acc, targets)
-            t0 = _time.perf_counter()
-            packed, injected = st.pack_superstep(active, R, P, t,
-                                                 targets, acc)
-            progress += injected
-            if packed is not None:
-                fresh, bias, pool_arr, fresh_ids = packed
-                handle = st.dispatch(fresh, bias, pool_arr, fringe,
-                                     fresh_ids, targets_i32, delta_cap,
-                                     t)
-            st.stats.host_s += _time.perf_counter() - t0
-            if packed is not None:
-                inflight.append(handle)
-            elif inflight:
-                st.stats.pipeline_stalls += 1   # device idles this round
-            if inflight and (len(inflight) >= cur_depth
-                             or packed is None):
-                harvested = _harvest_next(st, inflight, acc, targets)
-                progress += harvested
-                # adaptive depth: while a superstep admits less than
-                # half its capacity the draw view — not the device — is
-                # the bottleneck, and speculative packs only waste
-                # fixed-cost device calls; drop to lock-step until
-                # admissions recover. Deterministic: based solely on
-                # mirrored results.
-                cur_depth = 1 if 2 * harvested < active.size * t else depth
-            if progress == 0 and not inflight:
-                break   # starved: remaining vertices sit in other pools
-        while inflight:     # drain the pipeline before the safety net
-            _harvest_next(st, inflight, acc, targets)
+                with span(st, "hype.superstep.pack"):
+                    packed, injected = st.pack_superstep(active, R, P, t,
+                                                         targets, acc)
+                progress += injected
+                if packed is not None:
+                    fresh, bias, pool_arr, fresh_ids = packed
+                    with span(st, "hype.superstep.dispatch"):
+                        inflight.append(st.dispatch(
+                            fresh, bias, pool_arr, fringe, fresh_ids,
+                            targets_i32, delta_cap, t))
+                elif inflight:
+                    st.stats.pipeline_stalls += 1   # device idles this round
+                if inflight and (len(inflight) >= cur_depth
+                                 or packed is None):
+                    harvested = _harvest_next(st, inflight, acc, targets)
+                    progress += harvested
+                    # adaptive depth: while a superstep admits less than
+                    # half its capacity the draw view — not the device — is
+                    # the bottleneck, and speculative packs only waste
+                    # fixed-cost device calls; drop to lock-step until
+                    # admissions recover. Deterministic: based solely on
+                    # mirrored results.
+                    cur_depth = 1 if 2 * harvested < active.size * t else depth
+                if progress == 0 and not inflight:
+                    break   # starved: remaining vertices sit in other pools
+            while inflight:     # drain the pipeline before the safety net
+                _harvest_next(st, inflight, acc, targets)
     except membudget.DeviceOOM as exc:
         # memory fault mid-run: settle the pipeline, then enrich the
         # exception with everything the re-tiling retry loop needs —
@@ -619,21 +733,22 @@ def run_pipeline(hg: Hypergraph, k: int, p, make_state, engine: str,
         _teardown_pipeline(st, inflight)
         raise
 
-    # safety net: balance-fill any stragglers into underfull phases
-    rem_v = np.flatnonzero(st.assignment < 0)
-    if rem_v.size:
-        deficit = np.maximum(targets - acc, 0)
-        fill = np.repeat(np.arange(kG), deficit)[:rem_v.size]
-        st.assignment[rem_v[:fill.size]] = fill.astype(np.int32)
-    st.release_pools()
-    # the device image syncs at superstep boundaries only; the final
-    # injections' delta dies with the state (the host assignment is
-    # authoritative). Tests needing device/host parity flush explicitly
-    # through dispatch/harvest.
-    st.delta_ids, st.delta_vals = [], []
-    obs = membudget.observed_peak_bytes()
-    st.stats.peak_bytes_observed = (int(obs) if obs else
-                                    int(st.stats.peak_bytes_planned))
+    with span(st, "hype.finish"):
+        # safety net: balance-fill any stragglers into underfull phases
+        rem_v = np.flatnonzero(st.assignment < 0)
+        if rem_v.size:
+            deficit = np.maximum(targets - acc, 0)
+            fill = np.repeat(np.arange(kG), deficit)[:rem_v.size]
+            st.assignment[rem_v[:fill.size]] = fill.astype(np.int32)
+        st.release_pools()
+        # the device image syncs at superstep boundaries only; the final
+        # injections' delta dies with the state (the host assignment is
+        # authoritative). Tests needing device/host parity flush
+        # explicitly through dispatch/harvest.
+        st.delta_ids, st.delta_vals = [], []
+        obs = membudget.observed_peak_bytes()
+        st.stats.peak_bytes_observed = (int(obs) if obs else
+                                        int(st.stats.peak_bytes_planned))
     return st.assignment, st
 
 

@@ -29,9 +29,9 @@ from ..core.scoring import (_apply_host_injections, _gather_fresh_tiles,
                             _poison_guard, _stale_masked_prev)
 from .batched import BatchedParams
 from .pipeline import PipelineState, _CallArgs
-from .runtime import (BatchedStats, maybe_refine, run_pipeline as
-                      _run_pipeline, run_pipeline_budgeted as
-                      _run_pipeline_budgeted)
+from .runtime import (BatchedStats, maybe_refine, partition_root, span,
+                      run_pipeline as _run_pipeline,
+                      run_pipeline_budgeted as _run_pipeline_budgeted)
 
 
 @dataclasses.dataclass
@@ -552,6 +552,7 @@ def run_pipeline_budgeted(hg: Hypergraph, k: int, p: SuperstepParams):
         "hype_superstep", devices=0)
 
 
+@partition_root
 def hype_superstep_partition(hg: Hypergraph, k: int,
                              params: Optional[SuperstepParams] = None,
                              return_stats: bool = False):
@@ -594,7 +595,8 @@ def hype_superstep_partition(hg: Hypergraph, k: int,
         stats.fallbacks += 1
         return (assignment, stats) if return_stats else assignment
     assert (assignment >= 0).all()
-    assignment = maybe_refine(hg, k, params, assignment, st.stats)
+    with span(st, "hype.finish"):
+        assignment = maybe_refine(hg, k, params, assignment, st.stats)
     if return_stats:
         return assignment, st.stats
     return assignment

@@ -48,3 +48,15 @@ def test_readme_documents_the_commands():
     assert "benchmarks.bench_engine_scaling" in text   # bench repro
     assert "BENCH_engines.json" in text
     assert "xla_force_host_platform_device_count" in text
+
+
+def test_bare_names_resolve_in_the_source_trees_only():
+    """A bare file name in the docs resolves by a walk of the source
+    trees, never of the whole checkout, where test runs create and
+    delete directories while the walk runs."""
+    sys.path.insert(0, str(REPO / "tools"))
+    import check_docs
+
+    assert check_docs.in_source_tree("quickstart.py")      # examples/
+    assert not check_docs.in_source_tree("conftest.py")    # tests/
+    assert not check_docs.in_source_tree("no_such_module.py")

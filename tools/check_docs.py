@@ -16,6 +16,7 @@ documented entry point can never rot. The block must be self-contained.
 """
 from __future__ import annotations
 
+import os
 import pathlib
 import re
 import sys
@@ -23,6 +24,10 @@ import sys
 REPO = pathlib.Path(__file__).resolve().parent.parent
 DOCS = ["README.md", "DESIGN.md", "CHANGES.md", "ROADMAP.md",
         "benchmarks/README.md"]
+# where a bare file name may resolve: the tracked source trees only, so
+# the walk never enters directories that test runs create and delete
+# (temporary directories, caches) under the checkout
+SOURCE_TREES = ("src", "benchmarks", "bench", "examples", "tools")
 
 _LINK = re.compile(r"\[[^\]]*\]\(([^)#]+)(?:#[^)]*)?\)")
 _REF = re.compile(r"`([A-Za-z0-9_./-]+\.(?:py|md|json|yml))`")
@@ -42,12 +47,12 @@ def check_links() -> int:
                 continue
             # docs refer to code by doc-relative path, repo path, or
             # package path (`core/hype.py`); a bare module name
-            # (`hype.py`) resolves if the file exists anywhere — the
-            # point is catching renames, not pinning directories.
+            # (`hype.py`) resolves if the file exists in a source tree —
+            # the point is catching renames, not pinning directories.
             roots = (path.parent, REPO, REPO / "src" / "repro")
             if any((r / t).exists() for r in roots):
                 continue
-            if "/" not in t and list(REPO.rglob(t)):
+            if "/" not in t and in_source_tree(t):
                 continue
             bad.append(f"{doc}: broken reference -> {t}")
     if bad:
@@ -57,6 +62,16 @@ def check_links() -> int:
         return 1
     print(f"OK: all file references in {', '.join(DOCS)} resolve")
     return 0
+
+
+def in_source_tree(name: str) -> bool:
+    """Whether a file called ``name`` exists in one of ``SOURCE_TREES``.
+
+    ``os.walk`` skips a directory that vanishes while it is walked.
+    """
+    return any(name in files
+               for tree in SOURCE_TREES
+               for _, _, files in os.walk(REPO / tree))
 
 
 def extract_quickstart() -> str:
