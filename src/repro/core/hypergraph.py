@@ -16,6 +16,8 @@ padded) views it needs.
 from __future__ import annotations
 
 import dataclasses
+import os
+from concurrent.futures import ThreadPoolExecutor
 from typing import Iterable, Sequence
 
 import numpy as np
@@ -23,6 +25,12 @@ import numpy as np
 # Ids at or above 2**31 no longer fit int32; the decision is extracted so
 # the boundary can be tested without allocating 2-billion-row graphs.
 _INT32_LIMIT = 2**31
+
+# Neighbour pairs per chunk of ``vertex_adjacency``: a chunk's largest
+# int64 temporary stays at 8 MB, under glibc's 32 MB mmap ceiling, so
+# freed chunk buffers are reused from the heap instead of faulting in
+# fresh pages on every build.
+_ADJ_CHUNK_PAIRS = 1 << 20
 
 
 def csr_index_dtype(n: int, m: int):
@@ -165,13 +173,21 @@ class Hypergraph:
     def vertex_adjacency(self, max_expanded: int = 80_000_000):
         """CSR of unique neighbor lists N(v) for ALL vertices, memoized.
 
-        Built in one vectorized pass: every pin (v, e) contributes all
-        pins of e, and the (v, u) pairs are deduplicated globally — total
-        intermediate work is sum over edges of |e|^2. Returns
-        ``(indptr, indices)`` (self-loops excluded), or None when the
-        expansion would exceed ``max_expanded`` pairs (pathological hub
-        edges; callers fall back to per-batch deduplication).
+        Every pin (v, e) contributes all pins of e, so the expansion is
+        sum over edges of |e|^2 pairs. It is built per contiguous range of
+        owner vertices (``_ADJ_CHUNK_PAIRS`` pairs a chunk, bounds fixed
+        by the graph alone) on a thread pool of the host's cores; numpy
+        releases the GIL in the sorts, gathers and ufuncs that do the
+        work. Returns ``(indptr int64, indices int32)``: each row sorted
+        ascending and unique, self-loops excluded, the same whatever the
+        chunk or thread count. Returns None when the expansion would
+        exceed ``max_expanded`` pairs (pathological hub edges; callers
+        fall back to per-batch deduplication).
+
+        ``_adj_build`` records ``(chunks, workers)`` of this call's
+        build: ``(0, 0)`` on a memo hit or when the guard trips.
         """
+        object.__setattr__(self, "_adj_build", (0, 0))
         cache = self.__dict__.get("_adj_cache")
         if cache is None:
             cache = {}
@@ -182,24 +198,65 @@ class Hypergraph:
         if expanded > max_expanded:
             adj = None
         else:
-            from .scoring import gather_csr_rows   # numpy-only, no cycle
-            sizes = self.edge_sizes.astype(np.int64)
-            edge_of_pin = np.repeat(np.arange(self.m, dtype=np.int64),
-                                    sizes)
-            # expand: for pin j of edge e, all pins of e
-            nbr, owner_pin = gather_csr_rows(self.e2v_indptr,
-                                             self.e2v_indices, edge_of_pin)
-            nbr = nbr.astype(np.int64)
-            owner = self.e2v_indices[owner_pin].astype(np.int64)
-            keys = np.unique(owner * np.int64(self.n) + nbr)
-            ov, nb = keys // self.n, keys % self.n
-            keep = ov != nb
-            ov, nb = ov[keep], nb[keep]
-            indptr = np.zeros(self.n + 1, dtype=np.int64)
-            indptr[1:] = np.cumsum(np.bincount(ov, minlength=self.n))
-            adj = (indptr, nb.astype(np.int32))
+            adj = self._build_adjacency()
         cache[max_expanded] = adj               # frozen-dataclass memo
         return adj
+
+    def _build_adjacency(self):
+        """``vertex_adjacency``'s build, chunked over owner ranges."""
+        # pairs owned by each vertex: the sizes of its edges, summed
+        pin_pairs = self.edge_sizes[self.v2e_indices]
+        pin_cum = np.zeros(pin_pairs.size + 1, dtype=np.int64)
+        np.cumsum(pin_pairs, out=pin_cum[1:])
+        vcum = pin_cum[self.v2e_indptr]
+        n_chunks = -(-int(vcum[-1]) // _ADJ_CHUNK_PAIRS)
+        cuts = np.searchsorted(
+            vcum, np.arange(1, n_chunks, dtype=np.int64) * _ADJ_CHUNK_PAIRS)
+        bounds = np.unique(np.concatenate(([0], cuts, [self.n])))
+        chunks = list(zip(bounds[:-1].tolist(), bounds[1:].tolist()))
+
+        def rows(chunk):
+            return self._adjacency_rows(*chunk, vcum)
+
+        workers = min(len(os.sched_getaffinity(0)), len(chunks))
+        if workers <= 1:
+            parts = [rows(c) for c in chunks]
+        else:
+            with ThreadPoolExecutor(max_workers=workers) as pool:
+                parts = list(pool.map(rows, chunks))
+        object.__setattr__(self, "_adj_build", (len(chunks), workers))
+        indptr = np.zeros(self.n + 1, dtype=np.int64)
+        if parts:
+            np.cumsum(np.concatenate([c for c, _ in parts]), out=indptr[1:])
+            indices = np.concatenate([nb for _, nb in parts])
+        else:
+            indices = np.empty(0, dtype=np.int32)
+        return indptr, indices
+
+    def _adjacency_rows(self, v0: int, v1: int, vcum: np.ndarray):
+        """Row lengths and int32 neighbours of the owners ``[v0, v1)``."""
+        n = self.n
+        edges = self.v2e_indices[self.v2e_indptr[v0]:self.v2e_indptr[v1]]
+        starts = self.e2v_indptr[edges]
+        lens = self.e2v_indptr[edges + 1] - starts
+        out_start = np.cumsum(lens) - lens
+        total = int(vcum[v1] - vcum[v0])
+        # position of every pair's neighbour in e2v_indices
+        pos = np.arange(total, dtype=np.int64)
+        pos += np.repeat(starts - out_start, lens)
+        keys = np.repeat(np.arange(v1 - v0, dtype=np.int64) * n,
+                         np.diff(vcum[v0:v1 + 1]))
+        keys += self.e2v_indices[pos]
+        del pos
+        # e2v rows are sorted, so the keys come in long sorted runs
+        keys.sort(kind="stable")
+        keep = np.ones(total, dtype=bool)
+        np.not_equal(keys[1:], keys[:-1], out=keep[1:])
+        keys = keys[keep]
+        owner, nbr = np.divmod(keys, n)
+        keep = nbr != owner + v0
+        counts = np.bincount(owner[keep], minlength=v1 - v0)
+        return counts, nbr[keep].astype(np.int32)
 
     def device_adjacency(self, max_expanded: int = 80_000_000, *,
                          mesh=None):

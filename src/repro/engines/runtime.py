@@ -122,6 +122,10 @@ class BatchedStats:
     page_hits: int = 0              # chunk requests served LRU-resident
     page_evictions: int = 0         # chunks evicted to stay under budget
     page_bytes: int = 0             # total bytes uploaded by the pager
+    # vertex-adjacency build (``Hypergraph.vertex_adjacency``; both 0
+    # when the memo was hit or the expansion guard tripped):
+    adjacency_chunks: int = 0       # owner-range chunks built
+    adjacency_workers: int = 0      # threads that built them
     # refinement post-pass (None unless refine_passes > 0 ran):
     refine: Optional[object] = None     # core.refine.RefineStats
     # named host spans (``span``): name -> [inclusive wall seconds, count]
@@ -131,7 +135,8 @@ class BatchedStats:
     # ``merge`` keeps the max (or the non-default value) instead of adding
     _MERGE_MAX = ("loop_store_peak", "loop_state_bytes",
                   "peak_bytes_planned", "peak_bytes_observed",
-                  "device_image_bytes", "plan_rung", "resumed_at")
+                  "device_image_bytes", "plan_rung", "resumed_at",
+                  "adjacency_workers")
 
     def merge(self, other: "BatchedStats") -> "BatchedStats":
         """Combine two runs' counters into a new ``BatchedStats``.
@@ -286,6 +291,8 @@ class EngineRuntime:
         # scoring then falls back to per-batch dedup with cap_pins.
         with span(self, "hype.setup.adjacency"):
             self.adj = hg.vertex_adjacency()
+        (self.stats.adjacency_chunks,
+         self.stats.adjacency_workers) = getattr(hg, "_adj_build", (0, 0))
         # deterministic fault schedule: the param (shared instance across
         # a degradation ladder) or a FRESH parse of REPRO_FAULT_PLAN per
         # engine run, so every run of a chaos suite sees the full plan

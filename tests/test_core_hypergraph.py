@@ -218,3 +218,71 @@ def test_device_adjacency_ptr_dtype_propagation():
     assert indptr_dev.dtype == device_ptr_dtype(host[1].size)
     assert indptr_dev.dtype == jnp.int32          # tiny graph fits
     np.testing.assert_array_equal(np.asarray(indptr_dev), host[0])
+
+
+def _adjacency_oracle(hg):
+    """The single-pass formulation: one global unique over every pair."""
+    from repro.core.scoring import gather_csr_rows
+    sizes = hg.edge_sizes.astype(np.int64)
+    edge_of_pin = np.repeat(np.arange(hg.m, dtype=np.int64), sizes)
+    nbr, owner_pin = gather_csr_rows(hg.e2v_indptr, hg.e2v_indices,
+                                     edge_of_pin)
+    nbr = nbr.astype(np.int64)
+    owner = hg.e2v_indices[owner_pin].astype(np.int64)
+    keys = np.unique(owner * np.int64(hg.n) + nbr)
+    ov, nb = keys // hg.n, keys % hg.n
+    keep = ov != nb
+    ov, nb = ov[keep], nb[keep]
+    indptr = np.zeros(hg.n + 1, dtype=np.int64)
+    indptr[1:] = np.cumsum(np.bincount(ov, minlength=hg.n))
+    return indptr, nb.astype(np.int32)
+
+
+def _adjacency_graphs():
+    from repro.data.synthetic import powerlaw_hypergraph
+    return {
+        "powerlaw": powerlaw_hypergraph(600, 400, seed=3, max_edge=30,
+                                        max_degree=20),
+        # vertices 0, 4 and 7 in no edge; edges 1 and 3 empty
+        "isolated_and_empty": Hypergraph.from_edge_lists(
+            8, [[1, 2, 3], [], [5, 6, 2], [], [3, 5]]),
+        "one_edge_holds_all": Hypergraph.from_edge_lists(
+            9, [list(range(9)), [2, 4]]),
+        # 0, 1 and 2 share three edges: their pairs repeat across edges
+        "shared_edges": Hypergraph.from_edge_lists(
+            5, [[0, 1, 2], [2, 1, 0, 3], [4, 0, 1, 2], [1, 3]]),
+        "one_vertex": Hypergraph.from_edge_lists(1, [[0], [0], []]),
+    }
+
+
+@pytest.mark.parametrize("workers", [1, 3, 64])
+@pytest.mark.parametrize("chunk_pairs", [1 << 40, 7, 1])
+@pytest.mark.parametrize("graph", sorted(_adjacency_graphs()))
+def test_vertex_adjacency_matches_global_unique(monkeypatch, graph,
+                                                chunk_pairs, workers):
+    """The chunked build equals one global unique, bit for bit, for any
+    chunk size and worker count (64 workers: more than chunks)."""
+    from repro.core import hypergraph
+    monkeypatch.setattr(hypergraph, "_ADJ_CHUNK_PAIRS", chunk_pairs)
+    monkeypatch.setattr(hypergraph.os, "sched_getaffinity",
+                        lambda pid: set(range(workers)))
+    hg = _adjacency_graphs()[graph]
+    want = _adjacency_oracle(hg)
+    got = hg.vertex_adjacency()
+    assert got[0].dtype == np.int64 and got[1].dtype == np.int32
+    np.testing.assert_array_equal(got[0], want[0])
+    np.testing.assert_array_equal(got[1], want[1])
+    chunks, used = hg._adj_build
+    assert chunks >= 1 and 1 <= used <= min(workers, chunks)
+    if chunk_pairs == 1 << 40:
+        assert chunks == 1
+    # a memo hit builds nothing
+    assert hg.vertex_adjacency() is got
+    assert hg._adj_build == (0, 0)
+
+
+def test_vertex_adjacency_guard_returns_none():
+    hg = Hypergraph.from_edge_lists(6, [[0, 1, 2, 3], [3, 4, 5]])
+    assert hg.vertex_adjacency(max_expanded=24) is None     # 16 + 9 pairs
+    assert hg._adj_build == (0, 0)
+    assert hg.vertex_adjacency(max_expanded=25) is not None

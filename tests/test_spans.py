@@ -163,6 +163,21 @@ def test_merge_sums_spans():
     assert snap.spans["hype.grow"] == [3.0, 3]
 
 
+@pytest.mark.parametrize("engine", ENGINES)
+def test_stats_count_the_adjacency_build(monkeypatch, engine):
+    """The build's chunks and threads reach the call's stats; a call
+    that builds nothing (guard tripped) counts 0 of each."""
+    from repro.core import hypergraph
+    hg = github_like(0.05)
+    monkeypatch.setattr(hypergraph, "_ADJ_CHUNK_PAIRS", 4096)
+    _, st = ENGINES[engine](_fresh(hg), K, return_stats=True)
+    assert st.adjacency_chunks >= 2 and st.adjacency_workers >= 1
+    monkeypatch.setattr(Hypergraph, "vertex_adjacency",
+                        lambda self, max_expanded=0: None)
+    _, st = ENGINES[engine](_fresh(hg), K, return_stats=True)
+    assert st.adjacency_chunks == 0 and st.adjacency_workers == 0
+
+
 def _reader(metric):
     path = REPO / "bench" / "metrics" / f"{metric}.py"
     spec = importlib.util.spec_from_file_location(

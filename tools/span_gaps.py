@@ -19,7 +19,8 @@ it runs the cell's graph and engine as the benchmark does
 call under the profiler, ``--calls`` calls after it. It prints the
 traced call's table and, for each group of calls, the mean wall time,
 the mean span totals, the share of the root the root's children cover,
-and the cell's per-layer metrics that read the stats; the JSON summary
+each call's adjacency build (chunks, threads), and the cell's per-layer
+metrics that read the stats; the JSON summary
 is the last line, and is written to ``--out`` too.
 """
 from __future__ import annotations
@@ -97,9 +98,12 @@ def _group(calls: list, cell, harness) -> dict:
         v = harness.load_reader(cell.root, m["name"])(data)
         if v is not None:
             metrics[m["name"]] = v
+    builds = [(c.stats.adjacency_chunks, c.stats.adjacency_workers)
+              for c in ok]
     return {"calls": len(calls), "call_s": [c.seconds for c in calls],
             "mean_call_s": sum(c.seconds for c in calls) / len(calls),
-            "spans": spans, "children_cover": cover, "metrics": metrics}
+            "spans": spans, "children_cover": cover, "metrics": metrics,
+            "adjacency_builds": builds}
 
 
 def run_cell(workload: str, seed: int, calls: int) -> dict:
